@@ -1,8 +1,9 @@
 """Kernel micro-benchmarks: fused masked-L2 vs. reference (CPU interpret mode
 measures correctness-path speed only; the BlockSpec structure targets TPU).
 
-Also reports the analytic VMEM working set per tile so the kernel's fit can
-be checked against the 16 MiB v5e VMEM budget without hardware.
+Also reports the analytic VMEM block footprint per tile.  That is a lower
+bound: whether the kernel fits the chip is what the ahead-of-time compile in
+``tests/test_tpu_compile.py`` says.
 """
 from __future__ import annotations
 
@@ -35,18 +36,16 @@ def run():
         rows.append({
             "kernel": f"masked_l2_d{d}",
             "vmem_bytes": ws["total"],
-            "fits_16MiB": ws["fits_16MiB"],
         })
     dt = bench_xla_scan()
-    rows.append({"kernel": "masked_l2_xla_base_us", "vmem_bytes": round(dt * 1e6, 1),
-                 "fits_16MiB": True})
+    rows.append({"kernel": "masked_l2_xla_base_us", "vmem_bytes": round(dt * 1e6, 1)})
     return rows
 
 
 def main():
-    print("kernel,vmem_bytes_or_us,fits_16MiB")
+    print("kernel,vmem_bytes_or_us")
     for r in run():
-        print(f"{r['kernel']},{r['vmem_bytes']},{r['fits_16MiB']}")
+        print(f"{r['kernel']},{r['vmem_bytes']}")
 
 
 if __name__ == "__main__":

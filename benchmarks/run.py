@@ -56,7 +56,7 @@ def main() -> None:
 
     from . import kernel_bench
     for r in kernel_bench.run():
-        print(f"kernel_{r['kernel']},{r['vmem_bytes']},fits={r['fits_16MiB']}")
+        print(f"kernel_{r['kernel']},{r['vmem_bytes']},")
 
     from . import filter_bench
     for r in filter_bench.run():

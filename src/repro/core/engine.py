@@ -12,6 +12,7 @@ import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 # NOTE: repro.filter is imported lazily inside build_stats/shard_corpus —
@@ -59,7 +60,8 @@ class EngineConfig:
     n_lists: Optional[int] = None      # IVF lists (default sqrt(N))
     sample_frac: float = 0.02          # stats sample (paper: 1-5 %)
     alpha0: int = 4                    # initial post-filter expansion
-    nprobe0: int = 8
+    nprobe0: int = 8                   # post-filter first probe count; the
+                                       # executor floors it at 1/16 of the lists
     seed: int = 0
     default_k: int = 10                # warmed-up k for the jit'd searches
     attr_index: bool = True            # build the bitmap/range attribute index
@@ -680,24 +682,16 @@ class FilteredANNEngine:
         return self
 
     def _warm_buckets(self, k: int):
-        from ..index.flat import l2_topk
-
+        """Compile every scan shape the pre-filter executors dispatch
+        (``PreFilterExec.warm``: the Pallas kernel on a TPU, XLA elsewhere),
+        plus the ground-truth shapes."""
         n, d = self.vectors.shape
-        # the pre-filter executor pads query batches to pow2 with floor 8,
-        # so (8, p) is the shape every small-batch (and per-query) search hits
-        q = np.zeros((8, d), np.float32)
-        p = 16
-        while p <= 2 * n:
-            sub = np.zeros((min(p, 1 << 24), d), np.float32)
-            m = np.ones(sub.shape[0], bool)
-            l2_topk(q, sub, min(k, sub.shape[0]), m)
-            p *= 2
+        self.pre_exec.warm(k)
         q1 = np.zeros((1, d), np.float32)
-        l2_topk(q1, self.vectors, k)                      # ground-truth shape
+        l2_topk(q1, self.vectors, k)                      # ground-truth shapes
         l2_topk(q1, self.vectors, k, np.ones(n, bool))
-        # the large-passing-set branch runs the masked top-k over the FULL
-        # corpus with the pow2-padded (floor 8) query batch — warm it too
-        l2_topk(q, self.vectors, k, np.ones(n, bool))
+        # fit()'s oracle scans the corpus the pre-filter keeps on device
+        l2_topk(q1, self.pre_exec.scan_vectors, k, np.ones(n, bool))
 
     # ------------------------------------------------------------------
     def label_query(self, q: np.ndarray, pred: AnyPredicate, k: int = 10,
@@ -745,7 +739,8 @@ class FilteredANNEngine:
             mask = mask & alive_base
         t_mask = time.perf_counter() - t_m0
         true_sel = float(mask.mean())
-        _, ti = l2_topk(q, self.vectors, k, mask)             # exact ground truth
+        # exact ground truth, over the corpus the pre-filter keeps on device
+        _, ti = l2_topk(q, self.pre_exec.scan_vectors, k, mask)
         ti = np.asarray(ti)
         if live_dirty:
             r_pre = self.pre_exec.search_masked(q, mask, k)
@@ -1386,10 +1381,14 @@ class FilteredANNEngine:
         from ..filter import AttributeIndex, PredicateCache
 
         parts = np.array_split(np.arange(self.vectors.shape[0]), n_shards)
+        devices = jax.devices()
         shards = []
         for s, ids in enumerate(parts):
             if ids.size == 0:
                 continue
+            # shard s scans on device s (round-robin when shards outnumber
+            # devices): its corpus is committed there once
+            dev = devices[s % len(devices)]
             v = np.ascontiguousarray(self.vectors[ids])
             c, m = self.cat[ids], self.num[ids]
             lists = min(n_lists or max(1, int(np.sqrt(ids.size))), ids.size)
@@ -1402,6 +1401,7 @@ class FilteredANNEngine:
                     v, c, m,
                     AttributeIndex.build(c, m, self.config.range_buckets),
                     PredicateCache(self.config.pred_cache_size),
+                    device=dev,
                 )
             # per-shard backend instances: backends index shard-local row
             # positions, so (like the attribute index) each shard builds its
@@ -1413,7 +1413,7 @@ class FilteredANNEngine:
             shards.append(CorpusShard(
                 shard_id=s,
                 ids=ids,
-                pre_exec=PreFilterExec(v, c, m),
+                pre_exec=PreFilterExec(v, c, m, device=dev),
                 post_exec=PostFilterExec(
                     ivf, c, m,
                     alpha0=self.config.alpha0, nprobe0=self.config.nprobe0,

@@ -18,6 +18,7 @@ paper-scale row counts remain selectable with ``scale="full"``.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Dict, Tuple
 
 import numpy as np
@@ -87,7 +88,9 @@ def _zipf_codes(
 def make_dataset(name: str, scale: str = "reduced", seed: int = 0) -> VectorDataset:
     paper_n, d, kinds = DATASETS[name]
     n = paper_n if scale == "full" else (_REDUCED_N[name] if scale == "reduced" else int(scale))
-    rng = np.random.default_rng(seed + hash(name) % 2**16)
+    # a fixed digest of the name: the salted hash() would make the data
+    # depend on PYTHONHASHSEED, i.e. differ between processes
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % 2**16)
     n_clusters = 64
     x, cluster = _mixture_vectors(rng, n, d, n_clusters)
 

@@ -9,15 +9,11 @@ Four small modules, one concern each:
 * :mod:`~repro.dist.fault`       — heartbeat + straggler monitors
   emitting :class:`FaultEvent` records for the launch driver.
 * :mod:`~repro.dist.elastic`     — mesh replanning after host loss.
-
-Importing this package also installs the ``jax.shard_map`` alias on jax
-versions that only ship ``jax.experimental.shard_map``.
 """
 from .collectives import (
     compressed_psum,
     merge_topk,
     psum_with_error_feedback,
-    shard_map,
 )
 from .elastic import replan_mesh
 from .fault import FaultEvent, HeartbeatMonitor, StragglerMitigator
@@ -43,5 +39,4 @@ __all__ = [
     "param_spec",
     "psum_with_error_feedback",
     "replan_mesh",
-    "shard_map",
 ]

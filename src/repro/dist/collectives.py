@@ -46,18 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax<0.5 only ships shard_map under jax.experimental; alias the modern
-# ``jax.shard_map`` spelling and re-export it so code that imports
-# repro.dist never depends on jax-import order elsewhere in the process.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map
-
-    jax.shard_map = shard_map
-
 __all__ = ["compressed_psum", "psum_with_error_feedback", "merge_topk",
-           "merge_topk_unique", "shard_map"]
+           "merge_topk_unique"]
 
 
 def _quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:

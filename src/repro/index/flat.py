@@ -2,9 +2,9 @@
 
 This is the pre-filtering executor's search engine (paper §4.1 implements
 pre-filtering with brute-force KNN) and the ground-truth oracle for recall
-measurement.  On TPU the masked dense scan is the idiomatic form (DESIGN.md
-§2); the fused Pallas kernel in :mod:`repro.kernels` implements the same
-contract and is validated against :func:`l2_topk`.
+measurement.  On TPU the masked dense scan is the idiomatic form; the fused
+Pallas kernel in :mod:`repro.kernels` implements the same contract and is
+validated against :func:`l2_topk`.
 """
 from __future__ import annotations
 
@@ -33,7 +33,10 @@ def l2_topk(
     """
     q2 = jnp.sum(queries * queries, axis=1, keepdims=True)   # (B, 1)
     x2 = jnp.sum(corpus * corpus, axis=1)                    # (N,)
-    d2 = q2 + x2[None, :] - 2.0 * queries @ corpus.T         # (B, N)
+    # HIGHEST: on a TPU the default f32 matmul runs in bf16 passes, which
+    # reorders near neighbours; this is an exact path
+    qx = jnp.matmul(queries, corpus.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = q2 + x2[None, :] - 2.0 * qx                         # (B, N)
     d2 = jnp.maximum(d2, 0.0)
     if mask is not None:
         d2 = jnp.where(mask[None, :], d2, jnp.inf)
@@ -74,7 +77,8 @@ def chunked_masked_topk(
         best_d, best_i = carry                                # (B,k), (B,k)
         x, m, start = inp
         x2 = jnp.sum(x * x, axis=1)
-        d2 = jnp.maximum(q2 + x2[None, :] - 2.0 * queries @ x.T, 0.0)
+        qx = jnp.matmul(queries, x.T, precision=jax.lax.Precision.HIGHEST)
+        d2 = jnp.maximum(q2 + x2[None, :] - 2.0 * qx, 0.0)
         d2 = jnp.where(m[None, :], d2, jnp.inf)
         ids = start + jnp.arange(chunk)
         cat_d = jnp.concatenate([best_d, d2], axis=1)

@@ -17,8 +17,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .masked_l2 import pl_scratch
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["decode_attention_kernel", "TS"]
 
@@ -91,9 +90,9 @@ def decode_attention_kernel(
         out_specs=pl.BlockSpec((1, 1, gq, dh), lambda i, j, t: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kv, gq, dh), jnp.float32),
         scratch_shapes=[
-            pl_scratch((gq, 128), jnp.float32),
-            pl_scratch((gq, 128), jnp.float32),
-            pl_scratch((gq, dh), jnp.float32),
+            pltpu.VMEM((gq, 128), jnp.float32),
+            pltpu.VMEM((gq, 128), jnp.float32),
+            pltpu.VMEM((gq, dh), jnp.float32),
         ],
         interpret=interpret,
     )(length.reshape(b, 1).astype(jnp.int32), q, k_cache, v_cache)

@@ -22,7 +22,8 @@ def masked_l2_topk_ref(
     """Exact masked top-k by squared L2.  Masked-out -> dist BIG, id -1."""
     q2 = jnp.sum(queries * queries, axis=1, keepdims=True)
     x2 = jnp.sum(corpus * corpus, axis=1)
-    d2 = jnp.maximum(q2 + x2[None, :] - 2.0 * queries @ corpus.T, 0.0)
+    qx = jnp.matmul(queries, corpus.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = jnp.maximum(q2 + x2[None, :] - 2.0 * qx, 0.0)
     d2 = jnp.where(mask.astype(bool)[None, :], d2, BIG)
     neg, idx = jax.lax.top_k(-d2, k)
     d = -neg

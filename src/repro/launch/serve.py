@@ -14,7 +14,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -22,6 +24,24 @@ import numpy as np
 from ..configs import get_config
 from ..models.model import Model
 from ..serve.engine import Request, ServeEngine
+
+
+def use_compile_cache(checkout: Path) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing else is set.  Otherwise it goes to a fixed
+    ``<checkout>/.jax_cache``: the path is part of the cache key, so a
+    directory that moved would never hit.  Every compile is cached, however
+    short (Pallas kernels compile in under JAX's default one-second floor).
+    Call from an entry point's ``main``, never at import.
+    """
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(checkout) / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def run_lm(args) -> dict:
@@ -173,6 +193,7 @@ def main(argv=None):
                     help="write the span tree as JSONL to this path")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache(Path(__file__).resolve().parents[3])
     if args.requests is None:
         args.requests = 8 if args.mode == "lm" else 400
     if args.mode == "lm":

@@ -209,14 +209,12 @@ def publish_kernel_dispatch(registry: MetricsRegistry) -> None:
 
 def publish_kernel_budget(registry: MetricsRegistry,
                           dims: Sequence[int] = (128, 256, 512)) -> None:
-    """Register the analytic VMEM working set (``kernel_bench``'s fit
-    check) so the obs snapshot carries the same per-kernel budget the
-    roofline ranking uses."""
+    """Register the analytic VMEM block footprint (``kernel_bench``'s
+    ``vmem_working_set``) so the obs snapshot carries the same per-kernel
+    budget the roofline ranking uses."""
     from ..kernels.ops import vmem_working_set
 
     for d in dims:
         ws = vmem_working_set(d)
         k = f"masked_l2_d{d}"
         registry.set_gauge("repro_kernel_vmem_bytes", ws["total"], kernel=k)
-        registry.set_gauge("repro_kernel_vmem_fits_16mib",
-                           int(ws["fits_16MiB"]), kernel=k)

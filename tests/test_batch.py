@@ -11,6 +11,9 @@ at test scale — the learned planner is free to (correctly) pick one strategy
 everywhere on a small corpus, which would leave one executor group untested.
 The batched MLP dispatch itself is covered row-vs-batch in test_planner.
 """
+from types import SimpleNamespace
+
+import jax
 import numpy as np
 import pytest
 
@@ -23,6 +26,7 @@ from repro.core import (
     Predicate,
     RangePred,
 )
+from repro.core.executors import PostFilterExec
 from repro.core.trainer import gen_queries
 from repro.data import make_dataset
 from repro.serve import ShardedANNEngine
@@ -135,6 +139,52 @@ def test_post_filter_budget_scales_with_selectivity(system):
     w_low, _ = eng.post_exec.initial_params(10, 0.01)
     w_high, _ = eng.post_exec.initial_params(10, 0.5)
     assert w_low > w_high
+
+
+@pytest.mark.parametrize("n_lists,nprobe", [(64, 8), (316, 32), (1000, 64), (4, 4)])
+def test_post_filter_first_probe_floor_follows_list_count(n_lists, nprobe):
+    """The first probe covers at least MIN_PROBE_FRAC of the lists: nprobe0
+    alone would probe a shrinking share of a growing index."""
+    index = SimpleNamespace(n=n_lists * n_lists, n_lists=n_lists)
+    px = PostFilterExec(index, None, None, nprobe0=8)
+    assert px.initial_params(10)[1] == nprobe
+    # a low selectivity still widens the probe past the floor
+    assert px.initial_params(10, 1e-6)[1] == n_lists
+
+
+def test_pre_filter_corpus_lives_on_the_scan_device(system):
+    _, eng, _, _ = system
+    sv = eng.pre_exec.scan_vectors
+    assert isinstance(sv, jax.Array)
+    assert sv.devices() == {jax.devices()[0]}
+    np.testing.assert_array_equal(np.asarray(sv), eng.pre_exec.vectors)
+
+
+def test_build_warms_every_pre_filter_scan_shape():
+    """After build(), a single-query pre-filter search compiles nothing, on
+    either scan branch: a compile inside fit()'s timed race would make the
+    exact plan lose every label it should win."""
+    ds = make_dataset("sift", scale="2000", seed=1)
+    eng = FilteredANNEngine(
+        ds.vectors, ds.cat, ds.num, EngineConfig(n_lists=16, seed=0)
+    ).build()
+    compiles = []
+
+    def listener(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        n = ds.vectors.shape[0]
+        for n_pass in (3, 10, 100, n // 4, n // 4 + 1, n):
+            mask = np.zeros(n, bool)
+            mask[:n_pass] = True
+            for ex in (eng.pre_exec, eng.ipre_exec):
+                ex.search_masked(ds.vectors[:1], mask, 10)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == []
 
 
 def test_batch_query_single_row_and_empty_predicate(system):

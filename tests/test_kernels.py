@@ -69,6 +69,35 @@ def test_masked_l2_padding_never_returned():
     assert (np.asarray(i_k) < 513).all()
 
 
+@pytest.mark.parametrize("k", [1, 10, 100])
+def test_masked_l2_ties_match_ref_ids(k):
+    """Duplicated corpus rows tie exactly: the kernel's k-round selection
+    must return the reference's ids (ascending distance, lower id first),
+    across query tiles and corpus tiles."""
+    rng = np.random.default_rng(40 + k)
+    base = _rand(rng, (40, 64))
+    x = base[rng.integers(0, 40, 1500)]
+    q = _rand(rng, (40, 64))
+    mask = rng.random(1500) < 0.6
+    d_k, i_k = masked_l2_topk(q, x, jnp.asarray(mask), k, interpret=True)
+    d_r, i_r = masked_l2_topk_ref(jnp.asarray(q), jnp.asarray(x), jnp.asarray(mask), k)
+    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_r))
+    np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_r), rtol=1e-5, atol=1e-4)
+
+
+def test_masked_l2_fewer_passing_than_k():
+    """k larger than the passing set: the tail is -1, exactly as the ref."""
+    rng = np.random.default_rng(5)
+    q = _rand(rng, (8, 32))
+    x = _rand(rng, (1100, 32))
+    mask = np.zeros(1100, bool)
+    mask[rng.choice(1100, 30, replace=False)] = True
+    _, i_k = masked_l2_topk(q, x, jnp.asarray(mask), 100, interpret=True)
+    _, i_r = masked_l2_topk_ref(jnp.asarray(q), jnp.asarray(x), jnp.asarray(mask), 100)
+    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_r))
+    assert (np.asarray(i_k)[:, 30:] == -1).all()
+
+
 @pytest.mark.parametrize("dtype", [np.float32, np.float16])
 def test_masked_l2_dtypes(dtype):
     rng = np.random.default_rng(3)

@@ -21,6 +21,7 @@ import pytest
 from repro.core import EngineConfig, FilteredANNEngine
 from repro.core.trainer import gen_queries
 from repro.data import make_dataset
+from repro.kernels.ops import vmem_working_set
 from repro.obs import (
     MetricsRegistry,
     NULL_TRACER,
@@ -143,8 +144,9 @@ def test_publish_kernel_budget_gauges():
     publish_kernel_budget(reg)
     for d in (128, 256, 512):
         k = f"masked_l2_d{d}"
-        assert reg.value("repro_kernel_vmem_bytes", kernel=k) > 0
-        assert reg.value("repro_kernel_vmem_fits_16mib", kernel=k) == 1
+        assert reg.value("repro_kernel_vmem_bytes", kernel=k) == (
+            vmem_working_set(d)["total"])
+        assert reg.series("repro_kernel_vmem_fits_16mib") == []
 
 
 # ----------------------------------------------------------------------
