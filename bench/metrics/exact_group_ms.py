@@ -1,0 +1,6 @@
+"""exact_group_ms (execute groups): mean wall of the program's pre/ipre group spans, ms."""
+from harness.readers import group_ms
+
+
+def read(v):
+    return group_ms(v, ("pre", "ipre"))
