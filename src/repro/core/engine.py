@@ -126,30 +126,24 @@ class QueryLabel:
     clauses: Optional[Tuple["QueryLabel", ...]] = None
 
 
-def _kernel_snapshot() -> Tuple[dict, dict]:
-    """Current (dispatch counts, dispatch wall) of the process-global kernel
-    ledger — an execute span annotates the DELTA across its body, so the
-    span carries exactly its own dispatches."""
+def _kernel_snapshot() -> dict:
+    """Current dispatch counts of the process-global kernel ledger — an
+    execute span annotates the DELTA across its body, so the span carries
+    exactly its own dispatches."""
     from ..kernels import ops
 
-    return ops.dispatch_counts(), ops.dispatch_wall()
+    return ops.dispatch_counts()
 
 
-def _annotate_kernel_delta(tracer, counts0: dict, wall0: dict) -> None:
-    """Attach per-kernel dispatch deltas since ``counts0``/``wall0`` to the
-    open span: counts on the deterministic ledger (``kernel_<name>`` attrs),
-    wall seconds on the real ledger (``kernel:<name>`` wall_detail keys —
-    what ``span_summary`` ranks against ``launch/roofline.py``)."""
+def _annotate_kernel_delta(tracer, counts0: dict) -> None:
+    """Attach per-kernel dispatch deltas since ``counts0`` to the open span
+    (``kernel_<name>`` attrs, on the deterministic ledger)."""
     from ..kernels import ops
 
     for name, n in ops.dispatch_counts().items():
         d = n - counts0.get(name, 0)
         if d:
             tracer.annotate(**{f"kernel_{name}": d})
-    for name, s in ops.dispatch_wall().items():
-        dw = s - wall0.get(name, 0.0)
-        if dw > 0.0:
-            tracer.add_wall(f"kernel:{name}", dw)
 
 
 def package_results(
@@ -221,9 +215,9 @@ def _execute_grouped(
                 # split of ex.search(): mask once, then the fused masked
                 # top-k — bit-identical (search() is exactly this pair),
                 # but the mask stays visible for the candidate-count attr
-                t0 = time.perf_counter()
-                m = ex.candidate_mask(pred)
-                res = ex.search_masked(queries[rows], m, k, t0=t0)
+                with tr.span("mask"):
+                    m = ex.candidate_mask(pred)
+                res = ex.search_masked(queries[rows], m, k, tracer=tr)
                 if tr.enabled:
                     tr.annotate(n_candidates=int(m.sum()))
                 out_d[rows], out_i[rows] = res.dists, res.ids
@@ -237,7 +231,7 @@ def _execute_grouped(
                      n_rows=len(post_rows)):
             d, ids, rnd = post_exec.search_rows(
                 queries[post_rows], [preds[i] for i in post_rows], k,
-                [float(ests[i]) for i in post_rows],
+                [float(ests[i]) for i in post_rows], tracer=tr,
             )
             out_d[post_rows], out_i[post_rows] = d, ids
             rounds[post_rows] = rnd
@@ -255,7 +249,8 @@ def _execute_grouped(
             with tr.span("group", decision="post", backend=str(bk),
                          knob=str(knob), n_rows=len(rows)):
                 if pred not in masks:
-                    masks[pred] = mask_ex.candidate_mask(pred)
+                    with tr.span("mask"):
+                        masks[pred] = mask_ex.candidate_mask(pred)
                 d, ids = backend_set.search_class(ci, queries[rows], masks[pred], k)
                 if tr.enabled:
                     tr.annotate(n_candidates=int(masks[pred].sum()))
@@ -310,12 +305,13 @@ def _live_execute_grouped(
 
     def seg_mask(pred) -> np.ndarray:
         if pred not in seg_masks:
-            seg_masks[pred] = pred.eval(live.seg_cat(), live.seg_num()) & alive_seg
+            with tr.span("mask", segment=True):
+                seg_masks[pred] = pred.eval(live.seg_cat(), live.seg_num()) & alive_seg
         return seg_masks[pred]
 
     def finish(rows, pred, bd, bi):
         if seg_exec is not None and seg_mask(pred).any():
-            res = seg_exec.search_masked(queries[rows], seg_mask(pred), k)
+            res = seg_exec.search_masked(queries[rows], seg_mask(pred), k, tracer=tr)
             si = np.where(res.ids >= 0, res.ids + base_n, -1).astype(np.int32)
             bd, bi = merge_topk(np.stack([bd, res.dists]), np.stack([bi, si]), k)
         out_d[rows], out_i[rows] = bd, bi
@@ -329,9 +325,9 @@ def _live_execute_grouped(
             bk, knob = _default_route_name(decision)
             with tr.span("group", decision=STRATEGY_NAMES[decision],
                          backend=bk, knob=knob, n_rows=len(rows), live=True):
-                m = ex.candidate_mask(pred)
-                mm = m[:base_n] & alive_base
-                res = ex.search_masked(queries[rows], mm, k)
+                with tr.span("mask"):
+                    mm = ex.candidate_mask(pred)[:base_n] & alive_base
+                res = ex.search_masked(queries[rows], mm, k, tracer=tr)
                 if tr.enabled:
                     tr.annotate(n_candidates=int(mm.sum()))
                 finish(rows, pred, res.dists, res.ids)
@@ -345,7 +341,7 @@ def _live_execute_grouped(
                      n_rows=len(post_rows), live=True):
             d, ids, rnd = post_exec.search_rows(
                 queries[post_rows], [preds[i] for i in post_rows], k,
-                [float(ests[i]) for i in post_rows], alive=alive_base,
+                [float(ests[i]) for i in post_rows], alive=alive_base, tracer=tr,
             )
             rounds[post_rows] = rnd
             groups = {}
@@ -367,7 +363,8 @@ def _live_execute_grouped(
             with tr.span("group", decision="post", backend=str(bk),
                          knob=str(knob), n_rows=len(rows), live=True):
                 if pred not in base_masks:
-                    base_masks[pred] = mask_ex.candidate_mask(pred)[:base_n] & alive_base
+                    with tr.span("mask"):
+                        base_masks[pred] = mask_ex.candidate_mask(pred)[:base_n] & alive_base
                 d, ids = backend_set.search_class(ci, queries[rows], base_masks[pred], k)
                 if tr.enabled:
                     tr.annotate(n_candidates=int(base_masks[pred].sum()))
@@ -1440,20 +1437,20 @@ class FilteredANNEngine:
             decisions = np.array([decision], np.int32)
             routes = np.array([route], np.int32)
             with tr.span("execute", n_queries=1, k=int(k), live=True):
-                kc0, kw0 = _kernel_snapshot() if tr.enabled else ({}, {})
+                kc0 = _kernel_snapshot() if tr.enabled else {}
                 d, ids, rounds = _live_execute_grouped(
                     self.pre_exec, self.ipre_exec, self.post_exec,
                     q, [pred], k, decisions, np.array([est]), live,
                     routes=routes, backend_set=self.backend_set, tracer=tr,
                 )
                 if tr.enabled:
-                    _annotate_kernel_delta(tr, kc0, kw0)
+                    _annotate_kernel_delta(tr, kc0)
             share = time.perf_counter() - t0 + plan_overhead
             return package_results(d, ids, rounds, [plan], share,
                                    plan_overhead)[0]
         with tr.span("execute", n_queries=1, k=int(k), live=False,
                      decision=STRATEGY_NAMES[decision]):
-            kc0, kw0 = _kernel_snapshot() if tr.enabled else ({}, {})
+            kc0 = _kernel_snapshot() if tr.enabled else {}
             if decision == INDEXED_PRE:
                 res = self.ipre_exec.search(q, pred, k)
             elif decision == PRE_FILTER:
@@ -1469,7 +1466,7 @@ class FilteredANNEngine:
                 # the estimate also *parameterises* the chosen executor
                 res = self.post_exec.search(q, pred, k, est_selectivity=est)
             if tr.enabled:
-                _annotate_kernel_delta(tr, kc0, kw0)
+                _annotate_kernel_delta(tr, kc0)
         if not res.backend:
             res.backend, res.knob = plan.backend, plan.knob
         res.elapsed += plan_overhead   # end-to-end includes planning (paper §4.1)
@@ -1488,7 +1485,7 @@ class FilteredANNEngine:
         t0 = time.perf_counter()
         with tr.span("execute", n_queries=1, k=int(k), live=dirty,
                      decision="dnf", n_clauses=plan.n_clauses):
-            kc0, kw0 = _kernel_snapshot() if tr.enabled else ({}, {})
+            kc0 = _kernel_snapshot() if tr.enabled else {}
             qq = q[exp_rows]
             if dirty:
                 d, ids, rounds = _live_execute_grouped(
@@ -1504,7 +1501,7 @@ class FilteredANNEngine:
                 )
             d, ids, rounds = collapse_clause_results(d, ids, rounds, row_map, k)
             if tr.enabled:
-                _annotate_kernel_delta(tr, kc0, kw0)
+                _annotate_kernel_delta(tr, kc0)
         share = time.perf_counter() - t0 + plan_overhead
         return package_results(d, ids, rounds, [plan], share, plan_overhead)[0]
 
@@ -1526,39 +1523,40 @@ class FilteredANNEngine:
         """
         queries = np.atleast_2d(np.asarray(queries, np.float32))
         b = len(preds)
-        plans, plan_overhead = self.make_plan_batch(preds, k)
-        plan_share = plan_overhead / max(b, 1)
-        exp_rows, exp_preds, decisions, ests, routes, row_map = (
-            expand_for_execution(preds, plans))
-        # no DNF rows: the expansion is the identity and execution below is
-        # bit-identical to the historical whole-predicate batch path
-        identity = len(exp_preds) == b and all(len(m) == 1 for m in row_map)
-        xq = queries if identity else queries[exp_rows]
-        t0 = time.perf_counter()
         live = getattr(self, "live", None)
         tr = getattr(self, "tracer", NULL_TRACER)
-        with tr.span("execute", n_queries=b, k=int(k),
-                     live=bool(live is not None and live.dirty)):
-            kc0, kw0 = _kernel_snapshot() if tr.enabled else ({}, {})
-            if live is not None and live.dirty:
-                d, ids, rounds = _live_execute_grouped(
-                    self.pre_exec, self.ipre_exec, self.post_exec,
-                    xq, exp_preds, k, decisions, ests, live,
-                    routes=routes, backend_set=self.backend_set, tracer=tr,
-                )
-            else:
-                d, ids, rounds = _execute_grouped(
-                    self.pre_exec, self.ipre_exec, self.post_exec,
-                    xq, exp_preds, k, decisions, ests,
-                    routes=routes, backend_set=self.backend_set, tracer=tr,
-                )
-            if not identity:
-                d, ids, rounds = collapse_clause_results(
-                    d, ids, rounds, row_map, k)
-            if tr.enabled:
-                _annotate_kernel_delta(tr, kc0, kw0)
-        share = (time.perf_counter() - t0) / max(b, 1) + plan_share
-        return package_results(d, ids, rounds, plans, share, plan_share)
+        with tr.span("batch_query", n_queries=b, k=int(k)):
+            plans, plan_overhead = self.make_plan_batch(preds, k)
+            plan_share = plan_overhead / max(b, 1)
+            exp_rows, exp_preds, decisions, ests, routes, row_map = (
+                expand_for_execution(preds, plans))
+            # no DNF rows: the expansion is the identity and execution below
+            # is bit-identical to the historical whole-predicate batch path
+            identity = len(exp_preds) == b and all(len(m) == 1 for m in row_map)
+            xq = queries if identity else queries[exp_rows]
+            t0 = time.perf_counter()
+            with tr.span("execute", n_queries=b, k=int(k),
+                         live=bool(live is not None and live.dirty)):
+                kc0 = _kernel_snapshot() if tr.enabled else {}
+                if live is not None and live.dirty:
+                    d, ids, rounds = _live_execute_grouped(
+                        self.pre_exec, self.ipre_exec, self.post_exec,
+                        xq, exp_preds, k, decisions, ests, live,
+                        routes=routes, backend_set=self.backend_set, tracer=tr,
+                    )
+                else:
+                    d, ids, rounds = _execute_grouped(
+                        self.pre_exec, self.ipre_exec, self.post_exec,
+                        xq, exp_preds, k, decisions, ests,
+                        routes=routes, backend_set=self.backend_set, tracer=tr,
+                    )
+                if not identity:
+                    d, ids, rounds = collapse_clause_results(
+                        d, ids, rounds, row_map, k)
+                if tr.enabled:
+                    _annotate_kernel_delta(tr, kc0)
+            share = (time.perf_counter() - t0) / max(b, 1) + plan_share
+            return package_results(d, ids, rounds, plans, share, plan_share)
 
     # ------------------------------------------------------------------
     def ground_truth(self, q: np.ndarray, pred: AnyPredicate, k: int = 10) -> np.ndarray:

@@ -33,6 +33,7 @@ import numpy as np
 
 from ..index.ivf import IVFIndex
 from ..kernels.ops import fused_masked_topk
+from ..obs.trace import NULL_TRACER
 from .predicates import AnyPredicate
 from .util import next_pow2
 
@@ -140,61 +141,75 @@ class PreFilterExec:
 
     def search_masked(
         self, queries: np.ndarray, mask: np.ndarray, k: int,
-        t0: Optional[float] = None,
+        t0: Optional[float] = None, tracer=NULL_TRACER,
     ) -> SearchResult:
-        """Exact subset top-k under a precomputed candidate mask."""
+        """Exact subset top-k under a precomputed candidate mask.
+
+        Traced as two spans: ``scan_prep`` (the mask popcount, the passing
+        ids and the padding, up to the first jitted call; ``h2d_bytes`` is
+        what the call then hands to jitted functions from the host) and
+        ``scan_wait`` (from the first jitted call until the answers are
+        NumPy arrays on the host)."""
         if t0 is None:
             t0 = time.perf_counter()
         b = queries.shape[0]
         n = self.vectors.shape[0]
-        n_pass = int(mask.sum())
-        if n_pass == 0:
-            return SearchResult(
-                np.full((b, k), np.inf, np.float32),
-                np.full((b, k), -1, np.int32),
-                time.perf_counter() - t0,
-                self.strategy_name,
-            )
-        bp = next_pow2(b, floor=8)
-        qp = np.zeros((bp, self.vectors.shape[1]), np.float32)
-        qp[:b] = np.asarray(queries, np.float32)
-        if n_pass > self.FULL_SCAN_FRAC * n:
-            # large passing set: masked fused top-k over the whole corpus —
-            # ids come back global already
-            kk = min(k, n)
-            d, gids = fused_masked_topk(qp, self.scan_vectors, mask, kk)
-            d, gids = np.asarray(d)[:b], np.asarray(gids)[:b]
-            ids = np.full((b, k), -1, np.int32)
-            dist = np.full((b, k), np.inf, np.float32)
-            valid = gids >= 0
-            ids[:, :kk] = np.where(valid, gids, -1)
-            dist[:, :kk] = np.where(valid, d, np.inf)
-            return SearchResult(dist, ids, time.perf_counter() - t0, self.strategy_name)
-        # small passing set: gather the compacted subset, padded to the next
-        # power of two so the jit'd top-k sees O(log N) distinct shapes, not
-        # one per query (otherwise recompilation time pollutes the utility
-        # labels the planner learns from).  The query batch pads the same way
-        # (floor 8): the batched serving path stacks all queries sharing a
-        # predicate into ONE fused call, and pow2 query shapes keep the
-        # compile set O(log B) — with the floor making single-query and
-        # small-group calls share one shape (identical per-row results by
-        # construction).
-        idx = np.nonzero(mask)[0]
-        p = next_pow2(n_pass, floor=16)
-        idx_p = np.zeros(p, np.int32)
-        idx_p[:n_pass] = idx
-        valid_rows = np.zeros(p, bool)
-        valid_rows[:n_pass] = True
-        # k is capped by the padded bucket, not the passing count: a tiny
-        # passing set reuses the bucket's warmed shape (padding rows come
-        # back as id -1)
-        kk = min(k, p)
-        d, local = self._scan_subset(qp, idx_p, valid_rows, kk)
-        d, local = np.asarray(d)[:b], np.asarray(local)[:b]
+        with tracer.span("scan_prep"):
+            n_pass = int(mask.sum())
+            if n_pass == 0:
+                return SearchResult(
+                    np.full((b, k), np.inf, np.float32),
+                    np.full((b, k), -1, np.int32),
+                    time.perf_counter() - t0,
+                    self.strategy_name,
+                )
+            bp = next_pow2(b, floor=8)
+            qp = np.zeros((bp, self.vectors.shape[1]), np.float32)
+            qp[:b] = np.asarray(queries, np.float32)
+            full = n_pass > self.FULL_SCAN_FRAC * n
+            if full:
+                # large passing set: masked fused top-k over the whole
+                # corpus — ids come back global already
+                kk = min(k, n)
+                h2d = qp.nbytes + mask.nbytes
+            else:
+                # small passing set: gather the compacted subset, padded to
+                # the next power of two so the jit'd top-k sees O(log N)
+                # distinct shapes, not one per query (otherwise
+                # recompilation time pollutes the utility labels the planner
+                # learns from).  The query batch pads the same way (floor
+                # 8): the batched serving path stacks all queries sharing a
+                # predicate into ONE fused call, and pow2 query shapes keep
+                # the compile set O(log B) — with the floor making
+                # single-query and small-group calls share one shape
+                # (identical per-row results by construction).
+                idx = np.nonzero(mask)[0]
+                p = next_pow2(n_pass, floor=16)
+                idx_p = np.zeros(p, np.int32)
+                idx_p[:n_pass] = idx
+                valid_rows = np.zeros(p, bool)
+                valid_rows[:n_pass] = True
+                # k is capped by the padded bucket, not the passing count: a
+                # tiny passing set reuses the bucket's warmed shape (padding
+                # rows come back as id -1)
+                kk = min(k, p)
+                h2d = qp.nbytes + idx_p.nbytes + valid_rows.nbytes
+            tracer.annotate(h2d_bytes=h2d, n_pass=n_pass, full_scan=full)
+        with tracer.span("scan_wait"):
+            if full:
+                d, found = fused_masked_topk(qp, self.scan_vectors, mask, kk)
+            else:
+                d, found = self._scan_subset(qp, idx_p, valid_rows, kk)
+            d, found = np.asarray(d)[:b], np.asarray(found)[:b]
         ids = np.full((b, k), -1, np.int32)
         dist = np.full((b, k), np.inf, np.float32)
-        valid = local >= 0
-        ids[:, :kk] = np.where(valid, idx[np.minimum(np.maximum(local, 0), n_pass - 1)], -1)
+        valid = found >= 0
+        if full:
+            ids[:, :kk] = np.where(valid, found, -1)
+        else:
+            # subset-local positions back to corpus ids
+            ids[:, :kk] = np.where(
+                valid, idx[np.minimum(np.maximum(found, 0), n_pass - 1)], -1)
         dist[:, :kk] = np.where(valid, d, np.inf)
         return SearchResult(dist, ids, time.perf_counter() - t0, self.strategy_name)
 
@@ -299,6 +314,7 @@ class PostFilterExec:
         k: int,
         ests: Sequence[Optional[float]],
         alive: Optional[np.ndarray] = None,
+        tracer=NULL_TRACER,
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Row-faithful batched post-filter search (per-row predicates).
 
@@ -314,6 +330,12 @@ class PostFilterExec:
         vectorised predicate evaluation per distinct predicate instead of a
         Python loop over rows.  Because ``IVFIndex.search`` is row-independent,
         batched results are identical to B independent calls, only cheaper.
+        Each group dispatch of a round is traced as one ``ivf_round`` span
+        (the IVF scan, the predicate on its candidates and the pick of the
+        first k that pass), with the corpus vectors it scored
+        (``n_scored``), the candidates it returned (``n_retrieved``) and
+        those that passed the predicate and ``alive`` (``n_pass``).
+
         Returns ``(dists (B, k), ids (B, k), expansion_rounds (B,))``.
         """
         b = q.shape[0]
@@ -327,42 +349,50 @@ class PostFilterExec:
         # a row pays at most max_rounds IVF dispatches: the initial search
         # plus up to max_rounds - 1 doubling rounds
         pending = np.arange(b) if self.max_rounds > 0 else np.empty(0, np.int64)
+        it = 0
         # predicates evaluated lazily on retrieved candidates only
         while pending.size:
             groups: dict = {}
             for qi in pending:
                 groups.setdefault((int(want[qi]), int(nprobe[qi])), []).append(int(qi))
             for (w, npb), rows_l in groups.items():
-                rows = np.asarray(rows_l)
-                d, ids = self.index.search(q[rows], w, nprobe=npb)
-                # one predicate evaluation per distinct predicate in the group
-                keep = np.zeros(ids.shape, bool)
-                bypred: dict = {}
-                for j, qi in enumerate(rows_l):
-                    bypred.setdefault(preds[qi], []).append(j)
-                for p, js in bypred.items():
-                    flat = ids[js].reshape(-1)
-                    pos = flat >= 0
-                    kp = np.zeros(flat.size, bool)
-                    if pos.any():
-                        kp[pos] = p.eval(self.cat[flat[pos]], self.num[flat[pos]])
-                        if alive is not None:
-                            kp[pos] &= alive[flat[pos]]
-                    keep[js] = kp.reshape(len(js), -1)
-                # first k passing candidates per row, in distance order: a
-                # stable argsort of ~keep floats passing slots to the front
-                # without reordering among themselves
-                kk = min(k, ids.shape[1])
-                order = np.argsort(~keep, axis=1, kind="stable")[:, :kk]
-                sel_i = np.take_along_axis(ids, order, axis=1)
-                sel_d = np.take_along_axis(d, order, axis=1)
-                sel_keep = np.take_along_axis(keep, order, axis=1)
-                blk_i = np.full((rows.size, k), -1, np.int32)
-                blk_d = np.full((rows.size, k), np.inf, np.float32)
-                blk_i[:, :kk] = np.where(sel_keep, sel_i, -1)
-                blk_d[:, :kk] = np.where(sel_keep, sel_d, np.inf)
-                out_i[rows] = blk_i
-                out_d[rows] = blk_d
+                with tracer.span("ivf_round", round=it, n_rows=len(rows_l), want=w,
+                             nprobe=npb):
+                    rows = np.asarray(rows_l)
+                    d, ids, n_scored = self.index.search_scored(q[rows], w, nprobe=npb)
+                    # one predicate evaluation per distinct predicate in the group
+                    keep = np.zeros(ids.shape, bool)
+                    bypred: dict = {}
+                    for j, qi in enumerate(rows_l):
+                        bypred.setdefault(preds[qi], []).append(j)
+                    for p, js in bypred.items():
+                        flat = ids[js].reshape(-1)
+                        pos = flat >= 0
+                        kp = np.zeros(flat.size, bool)
+                        if pos.any():
+                            kp[pos] = p.eval(self.cat[flat[pos]], self.num[flat[pos]])
+                            if alive is not None:
+                                kp[pos] &= alive[flat[pos]]
+                        keep[js] = kp.reshape(len(js), -1)
+                    if tracer.enabled:
+                        tracer.annotate(n_scored=n_scored,
+                                    n_retrieved=int((ids >= 0).sum()),
+                                    n_pass=int(keep.sum()))
+                    # first k passing candidates per row, in distance order: a
+                    # stable argsort of ~keep floats passing slots to the front
+                    # without reordering among themselves
+                    kk = min(k, ids.shape[1])
+                    order = np.argsort(~keep, axis=1, kind="stable")[:, :kk]
+                    sel_i = np.take_along_axis(ids, order, axis=1)
+                    sel_d = np.take_along_axis(d, order, axis=1)
+                    sel_keep = np.take_along_axis(keep, order, axis=1)
+                    blk_i = np.full((rows.size, k), -1, np.int32)
+                    blk_d = np.full((rows.size, k), np.inf, np.float32)
+                    blk_i[:, :kk] = np.where(sel_keep, sel_i, -1)
+                    blk_d[:, :kk] = np.where(sel_keep, sel_d, np.inf)
+                    out_i[rows] = blk_i
+                    out_d[rows] = blk_d
+            it += 1
             got = (out_i[pending] >= 0).sum(1)
             exhausted = (want[pending] >= n) & (nprobe[pending] >= n_lists)
             more = (got < k) & ~exhausted & (rounds[pending] + 1 < self.max_rounds)

@@ -12,7 +12,6 @@ semantics:
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Optional, Tuple
 
@@ -75,7 +74,19 @@ class IVFIndex:
         nprobe: int = 8,
         mask: Optional[np.ndarray] = None,
     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Returns (dists (B,k), ids (B,k)); unfilled slots have id -1/inf.
+        """Returns (dists (B,k), ids (B,k)); see :meth:`search_scored`."""
+        return self.search_scored(queries, k, nprobe=nprobe, mask=mask)[:2]
+
+    def search_scored(
+        self,
+        queries: np.ndarray,
+        k: int,
+        nprobe: int = 8,
+        mask: Optional[np.ndarray] = None,
+    ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """Returns (dists (B,k), ids (B,k), n_scored); unfilled slots have
+        id -1/inf, and ``n_scored`` counts the corpus vectors scored (the
+        probed lists' sizes summed over the rows).
         ``mask`` (N,) restricts results to passing points (applied DURING the
         scan — this is what post-filtering calls with mask=None and what the
         engine's fused path uses directly).
@@ -107,18 +118,18 @@ class IVFIndex:
         if b > 1 and b * worst_c > 8_000_000:
             chunk = max(1, 8_000_000 // max(worst_c, 1))
             parts = [
-                self.search(q[s : s + chunk], k, nprobe=nprobe, mask=mask)
+                self.search_scored(q[s : s + chunk], k, nprobe=nprobe, mask=mask)
                 for s in range(0, b, chunk)
             ]
             return (
                 np.concatenate([p[0] for p in parts]),
                 np.concatenate([p[1] for p in parts]),
+                sum(p[2] for p in parts),
             )
         # dispatch ledger for the obs layer: one record per actual scan
         # (chunked calls record in the leaves, not the splitting parent)
         from ..kernels.ops import record_dispatch
 
-        t0 = time.perf_counter()
         # query -> centroid distances.  Same fixed-shape GEMM discipline as
         # the list scans below — every call is (L, d) @ (d, 8) regardless of
         # batch size, so probe selection is batch-invariant too.
@@ -141,8 +152,8 @@ class IVFIndex:
         totals = counts.sum(1)                                          # (B,)
         c = int(totals.max()) if b else 0
         if c == 0:
-            record_dispatch("ivf_search", time.perf_counter() - t0)
-            return out_d, out_i
+            record_dispatch("ivf_search")
+            return out_d, out_i, 0
         # ragged probe segments -> right-padded (B, C) sorted-row indices,
         # preserving per-row segment order (flat repeat/cumsum construction,
         # O(total candidates) memory)
@@ -211,8 +222,8 @@ class IVFIndex:
         fin = np.isfinite(sd)
         out_d[:, :kk] = np.where(fin, sd, np.inf)
         out_i[:, :kk] = np.where(fin, si, -1)
-        record_dispatch("ivf_search", time.perf_counter() - t0)
-        return out_d, out_i
+        record_dispatch("ivf_search")
+        return out_d, out_i, t
 
     # ------------------------------------------------------------------
     # TPU-target path: fixed shapes, jit + vmap

@@ -6,7 +6,6 @@ signature as the :mod:`repro.kernels.ref` oracles.  ``interpret=None`` means
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -18,8 +17,7 @@ from .masked_l2 import KPAD, TN, TQ, masked_l2_topk_kernel
 
 __all__ = [
     "masked_l2_topk", "decode_attention", "fused_masked_topk",
-    "record_dispatch", "dispatch_counts", "dispatch_wall",
-    "reset_dispatch_stats", "vmem_working_set",
+    "record_dispatch", "dispatch_counts", "reset_dispatch_stats", "vmem_working_set",
 ]
 
 # ----------------------------------------------------------------------
@@ -28,30 +26,23 @@ __all__ = [
 # function only runs at trace time), in the plain-Python wrappers that
 # every serving dispatch goes through: ``fused_masked_topk`` here,
 # ``IVFIndex.search`` and ``BackendSet.search_class`` at their call
-# sites.  Counts are deterministic per trace; wall seconds are the real
-# ledger (dispatch-call time — device sync happens at the caller's
-# ``np.asarray``).
+# sites.  Counts are deterministic per trace.  There is no timer here: a
+# host clock around an asynchronous dispatch times the enqueue, not the
+# work; device time comes from a profiler trace.
 # ----------------------------------------------------------------------
 _DISPATCH_COUNTS: Dict[str, int] = {}
-_DISPATCH_WALL: Dict[str, float] = {}
 
 
-def record_dispatch(name: str, seconds: float = 0.0) -> None:
+def record_dispatch(name: str) -> None:
     _DISPATCH_COUNTS[name] = _DISPATCH_COUNTS.get(name, 0) + 1
-    _DISPATCH_WALL[name] = _DISPATCH_WALL.get(name, 0.0) + float(seconds)
 
 
 def dispatch_counts() -> Dict[str, int]:
     return {k: _DISPATCH_COUNTS[k] for k in sorted(_DISPATCH_COUNTS)}
 
 
-def dispatch_wall() -> Dict[str, float]:
-    return {k: _DISPATCH_WALL[k] for k in sorted(_DISPATCH_WALL)}
-
-
 def reset_dispatch_stats() -> None:
     _DISPATCH_COUNTS.clear()
-    _DISPATCH_WALL.clear()
 
 
 def vmem_working_set(d: int) -> dict:
@@ -130,14 +121,13 @@ def fused_masked_topk(
     call hits; wider pow2 batch shapes (16, 32, ...) compile once on first
     use and are cached for the rest of the process.
     """
-    t0 = time.perf_counter()
     if jax.default_backend() == "tpu" and k <= KPAD:
         out = masked_l2_topk(queries, corpus, mask, k)
     else:
         from ..index.flat import l2_topk
 
         out = l2_topk(queries, corpus, k, mask)
-    record_dispatch("fused_masked_topk", time.perf_counter() - t0)
+    record_dispatch("fused_masked_topk")
     return out
 
 

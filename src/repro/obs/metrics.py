@@ -196,15 +196,13 @@ def publish_stats(registry: MetricsRegistry, stats: Dict[str, Any],
 
 
 def publish_kernel_dispatch(registry: MetricsRegistry) -> None:
-    """Mirror the process-global kernel dispatch counters/wall accumulated
-    in ``repro.kernels.ops`` into gauges (gauges, not counters: the source
-    is cumulative already)."""
+    """Mirror the process-global kernel dispatch counters accumulated in
+    ``repro.kernels.ops`` into gauges (gauges, not counters: the source is
+    cumulative already)."""
     from ..kernels import ops
 
     for name, n in ops.dispatch_counts().items():
         registry.set_gauge("repro_kernel_dispatch_total", n, kernel=name)
-    for name, s in ops.dispatch_wall().items():
-        registry.set_gauge("repro_kernel_wall_seconds", s, kernel=name)
 
 
 def publish_kernel_budget(registry: MetricsRegistry,

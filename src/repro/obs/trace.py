@@ -9,15 +9,21 @@ request/batch.  Every span carries TWO strictly separated ledgers:
   the trace + engine state only, so the same trace + seed reproduces the
   span tree bit-for-bit (:meth:`Tracer.deterministic_tree` is what replay
   tests compare).
-* **wall** — measured seconds (``wall_s`` for the span body,
-  ``wall_detail`` for named sub-costs such as per-kernel time).  Real
-  clocks never leak into attrs.
+* **wall** — measured seconds on the host clock (``start_s`` and
+  ``wall_s``).  Real clocks never leak into attrs.
+
+An enabled tracer also opens a ``jax.profiler.TraceAnnotation`` named
+``repro:<span name>`` for exactly the life of each span, so a profiler
+trace taken around traced work holds the span tree on the device trace's
+clock: an idle gap on the device can be attributed to the innermost span
+the host was in.  Device time comes from that trace, never from host
+timers around asynchronous dispatches.
 
 ``NULL_TRACER`` is the default no-op wired into the engines: the serving
 path pays one context-manager enter/exit per instrumented stage and
-nothing else when tracing is off.  :func:`span_summary` aggregates a
-recorded tracer into a per-stage wall ranking — the roofline-in-practice
-view the Pallas-kernel push (ROADMAP open item 2) prioritises from.
+nothing else when tracing is off (no annotation is opened).
+:func:`span_summary` aggregates a recorded tracer into a per-stage wall
+ranking.
 """
 from __future__ import annotations
 
@@ -25,6 +31,8 @@ import dataclasses
 import json
 import time
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer", "NULL_TRACER", "span_summary"]
 
@@ -53,8 +61,8 @@ class Span:
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
     children: List["Span"] = dataclasses.field(default_factory=list)
     # real ledger — excluded from deterministic comparisons
+    start_s: float = 0.0                              # time.perf_counter() at entry
     wall_s: float = 0.0
-    wall_detail: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def deterministic(self) -> Dict[str, Any]:
         """The replay-comparable projection: structure + attrs, no wall."""
@@ -93,12 +101,6 @@ class Tracer:
         if self._stack:
             self._stack[-1].attrs.update({k: _clean(v) for k, v in attrs.items()})
 
-    def add_wall(self, key: str, seconds: float) -> None:
-        """Accumulate a named wall-clock sub-cost (real ledger only)."""
-        if self._stack:
-            d = self._stack[-1].wall_detail
-            d[key] = d.get(key, 0.0) + float(seconds)
-
     def current(self) -> Optional[Span]:
         return self._stack[-1] if self._stack else None
 
@@ -117,7 +119,8 @@ class Tracer:
 
     def write_jsonl(self, path) -> None:
         """One JSON object per span, depth-first; deterministic fields
-        first, wall clock under a separate ``wall`` key."""
+        first, the host clock under a separate ``wall`` key (start and end
+        in ``time.perf_counter()`` seconds, and the span's length)."""
         with open(path, "w") as f:
             for sp in self.spans():
                 f.write(json.dumps({
@@ -126,9 +129,9 @@ class Tracer:
                     "name": sp.name,
                     "attrs": {k: sp.attrs[k] for k in sorted(sp.attrs)},
                     "wall": {
+                        "start_s": round(sp.start_s, 9),
+                        "end_s": round(sp.start_s + sp.wall_s, 9),
                         "s": round(sp.wall_s, 9),
-                        "detail": {k: round(v, 9)
-                                   for k, v in sorted(sp.wall_detail.items())},
                     },
                 }) + "\n")
 
@@ -137,7 +140,7 @@ class Tracer:
 
 
 class _SpanContext:
-    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_t0")
+    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_ann")
 
     def __init__(self, tracer: Tracer, name: str, attrs: Dict[str, Any]):
         self._tracer, self._name, self._attrs = tracer, name, attrs
@@ -155,11 +158,15 @@ class _SpanContext:
         (parent.children if parent is not None else tr.roots).append(sp)
         tr._stack.append(sp)
         self._span = sp
-        self._t0 = time.perf_counter()
+        self._ann = TraceAnnotation("repro:" + self._name)
+        self._ann.__enter__()
+        sp.start_s = time.perf_counter()
         return sp
 
     def __exit__(self, *exc) -> bool:
-        self._span.wall_s += time.perf_counter() - self._t0
+        sp = self._span
+        sp.wall_s = time.perf_counter() - sp.start_s
+        self._ann.__exit__(*exc)
         self._tracer._stack.pop()
         return False
 
@@ -192,9 +199,6 @@ class NullTracer(Tracer):
     def annotate(self, **attrs) -> None:
         pass
 
-    def add_wall(self, key: str, seconds: float) -> None:
-        pass
-
 
 NULL_TRACER = NullTracer()
 
@@ -204,26 +208,20 @@ def span_summary(tracer: Tracer) -> List[Dict[str, Any]]:
 
     One row per span name with ``count``, inclusive ``wall_s``, and
     exclusive ``self_s`` (inclusive minus children — the stage's own
-    cost); per-kernel wall sub-costs recorded via ``add_wall`` surface as
-    ``kernel:<name>`` pseudo-stages, so this ranking and
-    ``launch/roofline.py`` score the same candidate list.  Sorted by
-    ``self_s`` descending (ties broken by name for determinism of the
-    row ORDER — the wall values themselves are the real ledger).
+    host cost).  Sorted by ``self_s`` descending (ties broken by name for
+    determinism of the row ORDER — the wall values themselves are the
+    real ledger).  Device time is not here: it comes from a profiler
+    trace, where the spans appear as ``repro:<name>`` annotations.
     """
     rows: Dict[str, Dict[str, Any]] = {}
 
-    def bump(name: str, wall: float, self_s: float, count: int = 1) -> None:
-        r = rows.setdefault(name, {"stage": name, "count": 0,
-                                   "wall_s": 0.0, "self_s": 0.0})
-        r["count"] += count
-        r["wall_s"] += wall
-        r["self_s"] += self_s
-
     for sp in tracer.spans():
         child_s = sum(c.wall_s for c in sp.children)
-        bump(sp.name, sp.wall_s, max(sp.wall_s - child_s, 0.0))
-        for key, s in sp.wall_detail.items():
-            bump(key, s, s, count=0)
+        r = rows.setdefault(sp.name, {"stage": sp.name, "count": 0,
+                                      "wall_s": 0.0, "self_s": 0.0})
+        r["count"] += 1
+        r["wall_s"] += sp.wall_s
+        r["self_s"] += max(sp.wall_s - child_s, 0.0)
     out = sorted(rows.values(), key=lambda r: (-r["self_s"], r["stage"]))
     for r in out:
         r["wall_s"] = round(r["wall_s"], 6)
